@@ -22,6 +22,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SECTIONS = [
     ("milp", "Fig 5: MILP solve time", "benchmarks.bench_milp"),
     ("engine", "Allocation engine portfolio vs per-event MILP (week trace)",
@@ -74,6 +76,7 @@ def _parse_args(argv):
 
 def main() -> None:
     want = _parse_args(sys.argv[1:])
+    enable_compile_cache()
     t_start = time.time()
     for key, desc, mod_name in SECTIONS:
         if want and key not in want:

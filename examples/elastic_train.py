@@ -21,6 +21,7 @@ from repro.configs import get_arch
 from repro.core import AllocationEngine, amdahl_curve, fragments_to_events, \
     generate_summit_like
 from repro.elastic import BFTrainerRuntime, ElasticTrainer, ManagedTrainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW
 
@@ -40,6 +41,7 @@ def main() -> None:
                     help="target train steps per Trainer")
     ap.add_argument("--hours", type=float, default=48.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     frags = generate_summit_like(n_nodes=6, duration=args.hours * 3600,
                                  seed=13)

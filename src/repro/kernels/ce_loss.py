@@ -9,6 +9,11 @@ scratch; the (rows, V) logits tile never round-trips to HBM in fp32.
 
 Inputs are the hidden states and the (vocab-sharded-friendly) embedding
 table, so the kernel also fuses the final projection matmul.
+
+Labels and losses travel as ``(T, 1)`` columns: a 1-D ``(rows,)`` block
+does not match the TPU's tiled layout of a 1-D int32 operand, and the
+v5e compiler refuses it.  Row and vocab tiles are sized by
+``tiling.tile_rows``.
 """
 from __future__ import annotations
 
@@ -19,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
+from repro.kernels.tiling import tile_rows
 
 NEG_INF = -2.0 ** 30
 
@@ -43,17 +48,16 @@ def _kernel(x_ref, w_ref, lbl_ref, loss_ref, m_ref, l_ref, gold_ref, *,
         jnp.int32, logits.shape, 1)
     logits = jnp.where(v_pos < vocab, logits, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+    m_prev = m_ref[...]                             # (rows, 1)
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.exp(
-        logits - m_new[:, None]).sum(axis=-1)
+    l_ref[...] = l_ref[...] * corr + jnp.exp(logits - m_new).sum(
+        axis=-1, keepdims=True)
     m_ref[...] = m_new
 
     # gold logit for labels that fall in this vocab block
-    lbl = lbl_ref[...]                              # (rows,)
-    hit = (v_pos == lbl[:, None])
-    gold_ref[...] += jnp.where(hit, logits, 0.0).sum(axis=-1)
+    hit = (v_pos == lbl_ref[...])                   # (rows, 1) labels
+    gold_ref[...] += jnp.where(hit, logits, 0.0).sum(axis=-1, keepdims=True)
 
     @pl.when(vi == nv - 1)
     def _finish():
@@ -68,13 +72,14 @@ def ce_loss(x: jax.Array, table: jax.Array, labels: jax.Array, *,
     Returns (T,) fp32 losses (mean-reduce outside)."""
     t, d = x.shape
     v = table.shape[0]
-    block_rows = min(block_rows, t)
-    block_v = min(block_v, v)
+    block_rows = min(tile_rows(block_rows, d * x.dtype.itemsize), t)
+    block_v = min(tile_rows(block_v, d * table.dtype.itemsize), v)
+    labels = labels.reshape(t, 1)
     pr = (-t) % block_rows
     pv = (-v) % block_v
     if pr:
         x = jnp.pad(x, ((0, pr), (0, 0)))
-        labels = jnp.pad(labels, (0, pr))
+        labels = jnp.pad(labels, ((0, pr), (0, 0)))
     if pv:
         table = jnp.pad(table, ((0, pv), (0, 0)))
     nr = x.shape[0] // block_rows
@@ -86,17 +91,13 @@ def ce_loss(x: jax.Array, table: jax.Array, labels: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda r, vi: (r, 0)),
             pl.BlockSpec((block_v, d), lambda r, vi: (vi, 0)),
-            pl.BlockSpec((block_rows,), lambda r, vi: (r,)),
+            pl.BlockSpec((block_rows, 1), lambda r, vi: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((block_rows,), lambda r, vi: (r,)),
-        out_shape=jax.ShapeDtypeStruct((x.shape[0],), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((block_rows, 1), lambda r, vi: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, table, labels)
-    return out[:t]
+    return out[:t, 0]

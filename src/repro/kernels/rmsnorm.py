@@ -1,6 +1,7 @@
 """Fused RMSNorm Pallas TPU kernel: one pass over rows, fp32 statistics,
 (1 + scale) gain — fuses what XLA would otherwise emit as several HBM
-round-trips for large d_model."""
+round-trips for large d_model.  The row tile is sized by
+``tiling.tile_rows``."""
 from __future__ import annotations
 
 import functools
@@ -10,7 +11,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
+from repro.kernels.tiling import tile_rows
 
 
 def _kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -30,7 +31,7 @@ def rms_norm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
     for s in orig_shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
+    block_rows = min(tile_rows(block_rows, d * x.dtype.itemsize), rows)
     pad = (-rows) % block_rows
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
@@ -45,7 +46,7 @@ def rms_norm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, scale)

@@ -92,15 +92,6 @@ def _block_abstract(defs_blocks, mesh):
 
 def _analyze(compiled, n_dev):
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        # older jax returns one properties dict per executable program;
-        # newer jax returns the dict directly.  Sum the numeric entries.
-        merged = {}
-        for c in cost:
-            for k, v in (c or {}).items():
-                if isinstance(v, (int, float)):
-                    merged[k] = merged.get(k, 0.0) + v
-        cost = merged
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     coll = parse_collectives(compiled.as_text())

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ServeEngine
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default="",
                     help="load params from a train.py checkpoint")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     model = build_model(cfg, remat=False)

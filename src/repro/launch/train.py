@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import get_arch
 from repro.elastic import ElasticTrainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW
 
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default="",
                     help="path to save the final params/opt state")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if cfg.n_layers > 16 and not args.arch.endswith("-smoke"):

@@ -48,6 +48,20 @@ def test_elastic_trainer_trains_and_rescales():
     assert np.mean(more[-5:]) < np.mean(losses[:3])
 
 
+def test_train_step_donates_params_and_optimizer_state():
+    """The step updates the state in place: the arrays passed in are
+    deleted, so old and new state are never live together."""
+    tr = small_trainer(seed=2)
+    tr.rescale(1)
+    tr.train_step()
+    old = jax.tree.leaves((tr.params, tr.opt_state))
+    m = tr.train_step()
+    assert np.isfinite(m.loss)
+    assert all(x.is_deleted() for x in old)
+    assert not any(x.is_deleted()
+                   for x in jax.tree.leaves((tr.params, tr.opt_state)))
+
+
 def test_elastic_trainer_measures_rescale_costs():
     tr = small_trainer(seed=1)
     tr.rescale(1)

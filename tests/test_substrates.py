@@ -135,15 +135,8 @@ def test_checkpoint_roundtrip(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)            # newer jax signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # jax 0.4.x signature
-
-
 def _mesh():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_sanitize_drops_nondivisible_axes():
@@ -166,7 +159,7 @@ def test_batch_spec_divisibility():
     m = _mesh()
     assert batch_spec((256, 4096), m, ("data",)) == P("data")
     assert batch_spec((1, 524288), m, ("data",)) == P(None)
-    m3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    m3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert batch_spec((256, 4096), m3, ("pod", "data")) == P(("pod", "data"))
     # batch_spec unwraps single-axis tuples; P("pod") == P(("pod",)) only
     # on newer jax, so compare against the unwrapped form directly.
