@@ -21,11 +21,10 @@ coalescing and preemption-stall bookkeeping apply identically.
 from __future__ import annotations
 
 import math
-import time
 from typing import Dict, List, Optional, Sequence
 
 from repro.core.loop import TrainerJob
-from repro.obs.telemetry import NULL_TELEMETRY
+from repro.obs.telemetry import NULL_TELEMETRY, Telemetry
 
 
 class ExecutionBackend:
@@ -235,35 +234,42 @@ class LiveBackend(ExecutionBackend):
             out.append(job)
         return out
 
+    def bind(self, jobs: Sequence[TrainerJob]) -> None:
+        # hand a live hub on to trainers still carrying the null default,
+        # so their regions (DESIGN.md §13) land beside the backend's
+        tel = self.telemetry
+        if isinstance(tel, Telemetry) and tel:
+            for m in self.managed.values():
+                if getattr(m.trainer, "telemetry", None) is NULL_TELEMETRY:
+                    m.trainer.telemetry = tel
+
     def refresh(self, job: TrainerJob, now: float) -> None:
         if self.measure_rescale_costs:
             job.r_up, job.r_dw = \
                 self.managed[job.id].trainer.measured_rescale_costs()
 
-    def _sync(self, job: TrainerJob, now: float = 0.0) -> None:
+    def _sync(self, job: TrainerJob) -> None:
         tr = self.managed[job.id].trainer
         if tr.n_nodes != len(job.nodes):
-            old = tr.n_nodes
-            t0 = time.perf_counter()
-            tr.rescale(len(job.nodes))
             tel = self.telemetry
-            if tel:
-                # measured physical rescale duration — the live-path
-                # analogue of the analytic r_up/r_dw model costs
-                wall = time.perf_counter() - t0
-                tel.observe("backend.rescale_ms", wall * 1e3)
-                tel.instant("backend", "rescale", now, job=job.id,
-                            old=old, new=len(job.nodes), wall_s=wall)
+            if not isinstance(tel, Telemetry):
+                tel = NULL_TELEMETRY     # a duck-typed sink keeps no region
+            # the physical rescale's wall (``backend.rescale_ms``), the
+            # live-path analogue of the analytic r_up/r_dw: a park's copy
+            # to the host, a resume's or reshard's enqueue of its copy
+            with tel.region("backend.rescale", job=job.id,
+                            old=tr.n_nodes, new=len(job.nodes)):
+                tr.rescale(len(job.nodes))
 
     def apply_allocation(self, job: TrainerJob, old_n: int,
                          now: float) -> None:
-        self._sync(job, now)
+        self._sync(job)
 
     def on_preempt(self, job: TrainerJob, taken: List[int],
                    now: float) -> None:
         # departed nodes are gone now — shrink (or park) immediately, even
         # if the re-allocation itself is coalesced
-        self._sync(job, now)
+        self._sync(job)
 
     def on_fail(self, job: TrainerJob, failed: List[int],
                 now: float) -> Optional[float]:
@@ -310,5 +316,5 @@ class LiveBackend(ExecutionBackend):
         m = self.managed[job.id]
         if m.trainer.n_nodes > 0:
             job.nodes = []
-            self._sync(job, now)      # park: snapshot to host, free devices
+            self._sync(job)      # park: snapshot to host, free devices
         job.nodes = []

@@ -10,6 +10,17 @@ so the MILP can be driven by real ``R^up/R^dw`` values.
 The step donates params and optimizer state (``make_train_step``), so the
 old and new state are never on the device together: at yi-6b widths with
 one layer that is what lets a float32 AdamW step fit one 16 GB chip.
+
+With a live telemetry hub (``repro.obs``) the trainer opens a region
+around each part of its host work (DESIGN.md §13): ``trainer.park.copy``;
+``trainer.resume`` / ``trainer.reshard`` ⊃ ``trainer.build``,
+``trainer.put_state``; ``trainer.step`` ⊃
+``trainer.state_wait``, ``trainer.next_batch``, ``trainer.put_batch``,
+``trainer.dispatch`` (``trainer.first_dispatch`` at a new node count),
+``trainer.loss_wait``.  A rescale to n > 0 only enqueues the state's copy
+to the device; with the hub live the next step waits for it first, in
+``trainer.state_wait``, so the copy has a span of its own.  With the
+null hub nothing waits and each region is one no-op context.
 """
 from __future__ import annotations
 
@@ -26,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager, Snapshot
 from repro.data import DataConfig, TokenPipeline
 from repro.models import Model
+from repro.obs.telemetry import NULL_TELEMETRY, Telemetry
 from repro.optim import AdamW, linear_scaling, warmup_cosine
 
 Pytree = Any
@@ -71,7 +83,8 @@ class ElasticTrainer:
     def __init__(self, model: Model, *, optimizer: Optional[AdamW] = None,
                  per_node_batch: int = 8, devices_per_node: int = 1,
                  base_lr_nodes: int = 1, seed: int = 0,
-                 warmup_steps: int = 20, total_steps: int = 10_000):
+                 warmup_steps: int = 20, total_steps: int = 10_000,
+                 telemetry=None):
         self.model = model
         self.optimizer = optimizer or AdamW()
         self.per_node_batch = per_node_batch
@@ -89,8 +102,15 @@ class ElasticTrainer:
         self.n_nodes = 0
         self.mesh: Optional[Mesh] = None
         self._jitted: Dict[int, Callable] = {}
-        self.last_rescale_s = 0.0
+        # node counts whose step a live hub has seen dispatched
+        self._stepped: set[int] = set()
+        # a rescale enqueued the state's copy and no step has waited for it
+        self._landing = False
         self.rescale_history: list[tuple[int, int, float]] = []
+        # observation sink (repro.obs); LiveBackend hands the loop's hub
+        # to a trainer still carrying the null default
+        self.telemetry = (telemetry if isinstance(telemetry, Telemetry)
+                          else NULL_TELEMETRY)
 
     # ------------------------------------------------------------------
 
@@ -107,18 +127,27 @@ class ElasticTrainer:
     # ------------------------------------------------------------------
 
     def rescale(self, n_nodes: int) -> float:
-        """Rescale to ``n_nodes`` (0 = waiting).  Returns wall seconds."""
+        """Rescale to ``n_nodes`` (0 = waiting).  Returns wall seconds:
+        for a park the copy to the host; for a resume or reshard the
+        enqueue of the copy to the device, which lands later."""
         t0 = time.perf_counter()
         old = self.n_nodes
         if n_nodes == old:
             return 0.0
+        tel = self.telemetry
         if n_nodes == 0:
             # hold state on host; release device mesh
-            self.params = Snapshot.take(self.params, self.step_count).tree
-            self.opt_state = Snapshot.take(self.opt_state,
-                                           self.step_count).tree
+            with tel.region("trainer.park.copy"):
+                self.params = Snapshot.take(self.params,
+                                            self.step_count).tree
+                self.opt_state = Snapshot.take(self.opt_state,
+                                               self.step_count).tree
             self.mesh = None
             self.n_nodes = 0
+            if tel:
+                tel.count("trainer.park_bytes", sum(
+                    x.nbytes for x in jax.tree.leaves((self.params,
+                                                       self.opt_state))))
             dt = time.perf_counter() - t0
             self.rescale_history.append((old, 0, dt))
             return dt
@@ -127,37 +156,57 @@ class ElasticTrainer:
             raise ValueError(
                 f"rescale to {n_nodes} nodes needs {n_dev} devices, "
                 f"only {len(jax.devices())} available")
-        if n_nodes not in self._jitted:
-            self.mesh, fn = self._build(n_nodes)
-            self._jitted[n_nodes] = (self.mesh, fn)
-        self.mesh, _ = self._jitted[n_nodes]
-        repl = NamedSharding(self.mesh, P())
-        self.params = jax.tree.map(lambda x: jax.device_put(x, repl),
-                                   self.params)
-        self.opt_state = jax.tree.map(lambda x: jax.device_put(x, repl),
-                                      self.opt_state)
-        self.n_nodes = n_nodes
+        with tel.region("trainer.resume" if old == 0 else "trainer.reshard"):
+            if n_nodes not in self._jitted:
+                with tel.region("trainer.build"):
+                    self._jitted[n_nodes] = self._build(n_nodes)
+            self.mesh, _ = self._jitted[n_nodes]
+            repl = NamedSharding(self.mesh, P())
+            with tel.region("trainer.put_state"):
+                self.params = jax.tree.map(
+                    lambda x: jax.device_put(x, repl), self.params)
+                self.opt_state = jax.tree.map(
+                    lambda x: jax.device_put(x, repl), self.opt_state)
+            self.n_nodes = n_nodes
+            self._landing = True
         dt = time.perf_counter() - t0
-        self.last_rescale_s = dt
         self.rescale_history.append((old, n_nodes, dt))
         return dt
 
     def train_step(self) -> TrainMetrics:
         assert self.n_nodes > 0, "Trainer is waiting (0 nodes)"
-        mesh, fn = self._jitted[self.n_nodes]
-        batch_np = self.pipeline.next_batch(self.n_nodes)
-        batch_sh = NamedSharding(mesh, P("data"))
-        batch = {k: jax.device_put(v, batch_sh) for k, v in batch_np.items()}
-        lr_scale = jnp.float32(linear_scaling(self.n_nodes,
-                                              self.base_lr_nodes))
-        t0 = time.perf_counter()
-        self.params, self.opt_state, loss = fn(
-            self.params, self.opt_state, batch, lr_scale)
-        loss = float(loss)
-        dt = time.perf_counter() - t0
+        tel = self.telemetry
+        n = self.n_nodes
+        mesh, fn = self._jitted[n]
+        with tel.region("trainer.step"):
+            if tel and self._landing:
+                # the rescale only enqueued the state's copy; the step
+                # could not start before it lands, so wait for it here,
+                # where the wait has a span of its own
+                with tel.region("trainer.state_wait"):
+                    jax.block_until_ready((self.params, self.opt_state))
+            self._landing = False
+            with tel.region("trainer.next_batch"):
+                batch_np = self.pipeline.next_batch(n)
+            with tel.region("trainer.put_batch"):
+                batch_sh = NamedSharding(mesh, P("data"))
+                batch = {k: jax.device_put(v, batch_sh)
+                         for k, v in batch_np.items()}
+                lr_scale = jnp.float32(linear_scaling(n, self.base_lr_nodes))
+            dispatch = "trainer.dispatch"
+            if tel and n not in self._stepped:
+                # the first call at a node count traces and compiles
+                self._stepped.add(n)
+                dispatch = "trainer.first_dispatch"
+            t0 = time.perf_counter()
+            with tel.region(dispatch):
+                self.params, self.opt_state, loss = fn(
+                    self.params, self.opt_state, batch, lr_scale)
+            with tel.region("trainer.loss_wait"):
+                loss = float(loss)
+            dt = time.perf_counter() - t0
         self.step_count += 1
-        return TrainMetrics(step=self.step_count, n_nodes=self.n_nodes,
-                            loss=loss,
+        return TrainMetrics(step=self.step_count, n_nodes=n, loss=loss,
                             samples=batch_np["tokens"].shape[0],
                             step_time_s=dt)
 
@@ -193,6 +242,7 @@ class ElasticTrainer:
                 lambda x: jax.device_put(x, repl), self.params)
             self.opt_state = jax.tree.map(
                 lambda x: jax.device_put(x, repl), self.opt_state)
+            self._landing = True
         self.step_count = int(meta.get("step", step))
         return self.step_count
 

@@ -4,15 +4,18 @@ One hub observes everything the control plane does — allocation
 decisions per solver arm, loop events, rescale durations, fault
 injections, checkpoint restores — as counters, gauges, streaming
 histograms (p50/p95/p99) and dual-clock spans (trace clock + wall
-clock).  The default is ``NULL_TELEMETRY``, a falsy no-op sink, so
-instrumented code paths are bit-identical to uninstrumented ones when
-telemetry is off (tests/test_obs.py pins this down).
+clock) — and the live trainer's host work as wall-clock regions, each
+also a ``jax.profiler`` span on the device trace's clock.  The default
+is ``NULL_TELEMETRY``, a falsy no-op sink, so instrumented code paths
+are bit-identical to uninstrumented ones when telemetry is off
+(tests/test_obs.py pins this down).
 
 Entry points:
 
 * ``Telemetry()`` — the live hub; pass it as ``telemetry=`` to
   ``AllocationEngine`` / ``ControlLoop`` / ``Simulator`` /
-  ``run_scenario`` / ``run_chaos``.
+  ``run_scenario`` / ``run_chaos``; ``BFTrainerRuntime`` hands it on
+  through ``LiveBackend`` to each ``ElasticTrainer``.
 * ``telemetry.write_chrome_trace(path)`` — Chrome trace-event JSON,
   loadable in Perfetto (https://ui.perfetto.dev).
 * ``telemetry.write_jsonl(path)`` — deterministic span/event stream

@@ -2,10 +2,10 @@
 (DESIGN.md §13).
 
 ``Telemetry`` is a passive sink — instrumented code calls ``count`` /
-``gauge`` / ``observe`` / ``span`` / ``instant`` / ``sample`` and the
-hub accumulates.  It never feeds back into decisions, so enabling it
-cannot change any allocation (the enabled-vs-disabled parity test in
-tests/test_obs.py).
+``gauge`` / ``observe`` / ``span`` / ``instant`` / ``sample`` /
+``region`` and the hub accumulates.  It never feeds back into
+decisions, so enabling it cannot change any allocation (the
+enabled-vs-disabled parity test in tests/test_obs.py).
 
 ``NullTelemetry`` is the default everywhere: every method is a no-op
 and the instance is *falsy*, so hot paths guard with ``if tel:`` and
@@ -18,13 +18,23 @@ after which only ~7%-resolution geometric buckets accumulate (bounded
 memory on month-scale replays).  Everything is deterministic — no
 randomness, no wall-clock reads — so same-seed replays produce
 bit-identical histogram state.
+
+``region`` is the one wall-clock verb: a context manager around a block
+of host code that opens a ``jax.profiler.TraceAnnotation`` named
+``bftrainer/<name>`` (so the block sits on the profiler's host plane, on
+the device trace's clock), keeps a :class:`Region` on the hub and feeds
+the histogram ``<name>_ms``.  Regions stay out of the deterministic
+span stream.
 """
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import math
-from typing import Dict, List, Optional
+import time
+from dataclasses import dataclass, field
+from typing import Any, Dict, List, Optional
 
 from repro.obs.spans import (
     KIND_COUNTER,
@@ -39,6 +49,27 @@ from repro.obs.spans import (
 #: a histogram overflows its exact-sample cap
 _GROWTH = 1.07
 _LOG_GROWTH = math.log(_GROWTH)
+
+#: profiler name prefix of a region (``bftrainer/trainer.step``)
+REGION_PREFIX = "bftrainer/"
+
+#: what ``region`` returns where nothing is recorded: reusable, reads no
+#: clock
+NULL_REGION = contextlib.nullcontext()
+
+
+@dataclass
+class Region:
+    """One wall-clock region: ``time.perf_counter_ns`` at its edges
+    (``t1_ns`` is None while it is open) and the id of the region it
+    opened inside.  ``id`` is its index in ``Telemetry.regions``, which
+    lists regions in the order they opened."""
+    id: int
+    parent: Optional[int]
+    name: str
+    t0_ns: int
+    t1_ns: Optional[int] = None
+    args: Dict[str, Any] = field(default_factory=dict)
 
 
 class Histogram:
@@ -152,7 +183,7 @@ class Histogram:
 
 
 class Telemetry:
-    """The hub.  All mutation goes through the six verbs below; exports
+    """The hub.  All mutation goes through the seven verbs below; exports
     (`summary` / `write_jsonl` / `write_chrome_trace`) are read-only."""
 
     enabled = True
@@ -163,11 +194,13 @@ class Telemetry:
         self.gauges: Dict[str, float] = {}
         self.histograms: Dict[str, Histogram] = {}
         self.events: List[SpanEvent] = []
+        self.regions: List[Region] = []
+        self._open: List[int] = []          # ids of the open regions
 
     def __bool__(self) -> bool:
         return True
 
-    # -- the six verbs -------------------------------------------------
+    # -- the seven verbs -----------------------------------------------
 
     def count(self, name: str, delta: float = 1.0) -> None:
         self.counters[name] = self.counters.get(name, 0.0) + delta
@@ -204,13 +237,34 @@ class Telemetry:
                                      float(t), float(t),
                                      value=float(value)))
 
+    @contextlib.contextmanager
+    def region(self, name: str, **args):
+        """Time the enclosed host code on the wall clock: a profiler
+        span ``bftrainer/<name>``, a :class:`Region` whose parent is the
+        innermost region open on this hub, and one sample of the
+        histogram ``<name>_ms``."""
+        # imported here: core/ imports obs and carries no JAX
+        from jax.profiler import TraceAnnotation
+        rec = Region(len(self.regions), self._open[-1] if self._open
+                     else None, name, time.perf_counter_ns(), args=args)
+        self.regions.append(rec)
+        self._open.append(rec.id)
+        try:
+            with TraceAnnotation(REGION_PREFIX + name):
+                yield rec
+        finally:
+            rec.t1_ns = time.perf_counter_ns()
+            self._open.pop()
+            self.observe(name + "_ms", (rec.t1_ns - rec.t0_ns) * 1e-6)
+
     def merge_from(self, other: "Telemetry", *, prefix: str = "") -> None:
         """Fold another hub into this one, optionally namespacing every
         metric with ``prefix`` (e.g. ``"pool3."``).  Counters and gauges
         add/overwrite, histograms merge sample-exactly where possible,
         and span events append in order — the federated layer calls this
         once per pool, in pool order, so fleet traces stay deterministic
-        (DESIGN.md §14)."""
+        (DESIGN.md §14).  Regions stay with their hub; their ``_ms``
+        histograms merge."""
         for name, v in other.counters.items():
             key = prefix + name
             self.counters[key] = self.counters.get(key, 0.0) + v
@@ -283,6 +337,9 @@ class NullTelemetry(Telemetry):
     def sample(self, name, t, value):
         pass
 
+    def region(self, name, **args):
+        return NULL_REGION
+
     def merge_from(self, other, *, prefix=""):
         pass
 
@@ -290,3 +347,4 @@ class NullTelemetry(Telemetry):
 #: the shared default sink.  Stateless (all verbs drop), so one module
 #: singleton can back every uninstrumented engine/loop at once.
 NULL_TELEMETRY = NullTelemetry()
+

@@ -9,22 +9,27 @@ Three invariant families:
 * **trace determinism** — same-seed replays (clean and chaos) emit
   byte-identical JSONL streams (the wall clock is excluded by default);
 * unit coverage for the pieces: streaming ``Histogram`` percentiles,
-  JSONL round-trip, Chrome-trace export shape, per-job timelines, and
-  the dataclass-derived ``as_dict`` serialization.
+  JSONL round-trip, Chrome-trace export shape, per-job timelines, the
+  dataclass-derived ``as_dict`` serialization, and the wall-clock
+  ``region`` verb (nothing under a null or foreign sink; nesting,
+  profiler names and ``_ms`` histograms on a live hub).
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import math
+import types
 
 import pytest
 
 from repro.chaos import ChaosSpec, run_chaos
 from repro.core import AllocationEngine, Simulator, fragments_to_events
+from repro.core.backend import LiveBackend
 from repro.core.engine import EngineStats
 from repro.core.loop import LoopStats, TrainerJob
 from repro.core.scaling import tab2_curve
+import repro.obs.telemetry as telemetry_mod
 from repro.obs import (
     NULL_TELEMETRY,
     Histogram,
@@ -204,6 +209,110 @@ def test_null_telemetry_is_falsy_noop():
     NULL_TELEMETRY.sample("x", 0.0, 1.0)
     assert NULL_TELEMETRY.counters == {}
     assert NULL_TELEMETRY.events == []
+
+
+# ---------------------------------------------------------------------------
+# Regions: wall-clock spans of host code
+# ---------------------------------------------------------------------------
+
+
+class _ForeignSink:
+    """A truthy duck-typed sink whose unknown verbs do nothing, as the
+    benchmark harness's event clock is."""
+
+    def __bool__(self):
+        return True
+
+    def __getattr__(self, name):
+        return lambda *args, **kw: None
+
+
+@pytest.fixture
+def profiler(monkeypatch):
+    """``names``: the profiler spans opened, in order.  Reading the clock
+    from the hub's module raises unless ``clock`` is set."""
+    import jax.profiler
+
+    seen = types.SimpleNamespace(names=[], clock=False)
+
+    class Annotation:
+        def __init__(self, name):
+            seen.names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    ticks = iter(range(0, 10**9, 1000))
+
+    def clock():
+        if not seen.clock:
+            raise AssertionError("the clock was read")
+        return next(ticks)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(telemetry_mod.time, "perf_counter_ns", clock)
+    return seen
+
+
+@pytest.mark.parametrize("sink", [NULL_TELEMETRY, NullTelemetry(),
+                                  _ForeignSink()],
+                         ids=["null-singleton", "null", "foreign"])
+def test_region_on_a_sink_that_records_nothing(sink, profiler):
+    if isinstance(sink, Telemetry):
+        ctx = sink.region("trainer.step", nodes=1)
+        assert ctx is telemetry_mod.NULL_REGION
+        with ctx as got:
+            with sink.region("trainer.dispatch"):
+                pass
+        assert got is None
+    # a live backend's rescale under this sink opens no region either
+    trainer = types.SimpleNamespace(n_nodes=0)
+    trainer.rescale = lambda n: setattr(trainer, "n_nodes", n)
+    backend = LiveBackend([types.SimpleNamespace(id=0, trainer=trainer)])
+    backend.telemetry = sink
+    backend._sync(types.SimpleNamespace(id=0, nodes=[4, 7]))
+    assert trainer.n_nodes == 2
+    assert profiler.names == []
+    if isinstance(sink, Telemetry):
+        assert sink.regions == [] and sink.histograms == {}
+
+
+def test_live_region_records_nesting_profiler_spans_and_histograms(
+        profiler):
+    profiler.clock = True
+    tel = Telemetry()
+    with tel.region("trainer.step", nodes=2) as outer:
+        with tel.region("trainer.next_batch"):
+            pass
+        with tel.region("trainer.dispatch"):
+            with tel.region("inner"):
+                pass
+    with pytest.raises(ValueError):
+        with tel.region("trainer.step"):
+            raise ValueError("the region still closes")
+    assert outer is tel.regions[0]
+    assert [(r.id, r.parent, r.name) for r in tel.regions] == [
+        (0, None, "trainer.step"), (1, 0, "trainer.next_batch"),
+        (2, 0, "trainer.dispatch"), (3, 2, "inner"),
+        (4, None, "trainer.step")]
+    assert tel.regions[0].args == {"nodes": 2}
+    assert profiler.names == ["bftrainer/" + r.name for r in tel.regions]
+    for r in tel.regions:
+        assert r.t1_ns is not None and r.t1_ns > r.t0_ns
+        if r.parent is not None:
+            p = tel.regions[r.parent]
+            assert p.t0_ns < r.t0_ns and r.t1_ns < p.t1_ns
+    assert tel._open == []
+    h = tel.histograms
+    assert h["trainer.step_ms"].count == 2
+    assert h["inner_ms"].count == 1
+    assert h["trainer.dispatch_ms"].max == pytest.approx(
+        (tel.regions[2].t1_ns - tel.regions[2].t0_ns) * 1e-6)
+    # regions stay out of the deterministic span stream
+    assert tel.events == [] and tel.to_jsonl().count("\n") <= 1
 
 
 # ---------------------------------------------------------------------------
