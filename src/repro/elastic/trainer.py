@@ -43,6 +43,14 @@ from repro.optim import AdamW, linear_scaling, warmup_cosine
 Pytree = Any
 
 
+def loss_and_grads(model: Model, mesh: Mesh, params, batch):
+    """``model.loss`` and its gradient, traced under ``mesh`` as the context
+    mesh, so layers that keep work on each shard's own rows (the dropless
+    MoE) can see the data axis."""
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        return jax.value_and_grad(model.loss)(params, batch)
+
+
 def make_train_step(model: Model, optimizer: AdamW, mesh: Mesh, *,
                     warmup_steps: int, total_steps: int) -> Callable:
     """The jitted data-parallel train step on ``mesh``.
@@ -56,7 +64,7 @@ def make_train_step(model: Model, optimizer: AdamW, mesh: Mesh, *,
     batch_sh = NamedSharding(mesh, P("data"))
 
     def step(params, opt_state, batch, lr_scale):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        loss, grads = loss_and_grads(model, mesh, params, batch)
         sched = warmup_cosine(opt_state.step, warmup_steps=warmup_steps,
                               total_steps=total_steps)
         new_params, new_opt = optimizer.update(

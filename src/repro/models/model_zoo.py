@@ -48,7 +48,7 @@ def _cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 class Model:
     def __init__(self, cfg: ArchConfig, *, model_shards: int = 1,
-                 dtype=jnp.float32, moe_strategy: str = "dense",
+                 dtype=jnp.float32, moe_strategy: str = "dropless",
                  remat: bool = True, long_serving: bool = False,
                  scan_unroll=1):
         self.cfg = cfg

@@ -1,16 +1,23 @@
 """Mixture-of-Experts MLP with top-k routing, shared experts and a
 load-balance auxiliary loss.
 
-Two execution strategies (selectable; see EXPERIMENTS.md §Perf):
+Three execution strategies (``moe_strategy``; see EXPERIMENTS.md §Perf):
 
+* ``dropless`` — the default.  The T·k token→expert assignments are sorted
+  by expert, each expert weight runs as one grouped matmul
+  (``jax.lax.ragged_dot``) over its contiguous block of rows, and the rows
+  are un-sorted and combined with the renormalised top-k gates.  No token
+  is dropped, shapes are static whatever the routing, and the routed
+  FLOPs are the active-expert count.  The expert set is
+  ``w_gate.shape[0]``.  Under a context mesh that splits the batch over
+  ``"data"`` alone (the elastic trainer's step), each shard sorts and
+  multiplies its own rows.
 * ``dense``    — every expert processes every token; outputs are combined
-  with the (sparse) gate weights.  Simple, numerically exact, and maps onto
-  expert sharding with a single all-reduce — but costs ``E/k`` times the
-  active-expert FLOPs.  This is the paper-faithful baseline path (the paper
-  treats Trainers as black boxes; MoE efficiency is our extension).
+  with the (sparse) gate weights.  The same function as ``dropless`` at
+  ``E/k`` times the routed FLOPs; the tests' oracle and the decode path
+  (one token per sequence, where sorting buys nothing).
 * ``capacity`` — classic dispatch/combine einsum formulation with a token
-  capacity per expert (drops overflow tokens).  HLO FLOPs drop to the
-  active-expert count; used by the optimized configuration.
+  capacity per expert (drops overflow tokens, so a different function).
 
 Expert weights are sharded over the ``model`` axis on the expert dimension
 when ``n_experts % model_shards == 0`` (expert parallelism), otherwise on
@@ -18,6 +25,8 @@ the per-expert hidden dimension (tensor parallelism inside each expert —
 e.g. granite's 40 experts on a 16-way axis).
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -53,23 +62,30 @@ def moe_defs(cfg: ArchConfig, model_shards: int = 1, dtype=jnp.float32) -> dict:
 
 
 def _route(p: dict, x2d: jax.Array, moe: MoEConfig):
-    """Returns (gates (T,E) sparse, aux_loss scalar)."""
+    """Returns (top_idx (T,k), renormalised top_vals (T,k), aux_loss)."""
     logits = (x2d.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
     top_vals, top_idx = jax.lax.top_k(probs, moe.top_k)     # (T, k)
     top_vals = top_vals / jnp.maximum(
         top_vals.sum(-1, keepdims=True), 1e-9
     )
-    gates = jnp.zeros_like(probs).at[
-        jnp.arange(x2d.shape[0])[:, None], top_idx
-    ].set(top_vals)
 
-    # Switch-style load-balance loss: E * sum_e f_e * P_e
+    # Switch-style load-balance loss: E * sum_e f_e * P_e, f_e the share
+    # of tokens with a nonzero gate on e (exact integer counts / T)
     e = moe.n_experts
-    frac_tokens = (gates > 0).astype(jnp.float32).mean(0) * (e / moe.top_k)
+    routed = jax.nn.one_hot(top_idx, e) * (top_vals > 0)[..., None]
+    frac_tokens = routed.sum((0, 1)) / x2d.shape[0] * (e / moe.top_k)
     frac_probs = probs.mean(0)
     aux = moe.router_aux_coef * e * jnp.sum(frac_tokens * frac_probs)
-    return gates, aux
+    return top_idx, top_vals, aux
+
+
+def _gates(top_idx: jax.Array, top_vals: jax.Array, e: int) -> jax.Array:
+    """The (T,E) gate matrix: top_vals at top_idx, zero elsewhere."""
+    t = top_idx.shape[0]
+    return jnp.zeros((t, e), top_vals.dtype).at[
+        jnp.arange(t)[:, None], top_idx
+    ].set(top_vals)
 
 
 def _experts_dense(p: dict, x2d: jax.Array, gates: jax.Array,
@@ -121,18 +137,148 @@ def _experts_capacity(p: dict, x2d: jax.Array, gates: jax.Array,
     return y.reshape(n_groups * g, d)[:t]
 
 
+def _grouped(rows: jax.Array, w: jax.Array, group_sizes: jax.Array):
+    """(N,a) rows, sorted into groups of ``group_sizes``, times their
+    group's (a,b) slice of ``w`` (G,a,b): one ragged dot, float32 out."""
+    return jax.lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x: jax.Array, order: jax.Array, inv: jax.Array,
+              k: int) -> jax.Array:
+    """Rows of ``x`` (T,d) repeated for each of their ``k`` assignments and
+    put in ``order`` (T·k,), whose inverse permutation is ``inv``.  Backward
+    gathers with ``inv`` and sums each token's ``k`` rows: no scatter-add."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_bwd(k, inv, g):
+    return g[inv].reshape(-1, k, g.shape[-1]).sum(1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _down_combine(h: jax.Array, w_down: jax.Array, group_sizes: jax.Array,
+                  top_vals: jax.Array, order: jax.Array,
+                  inv: jax.Array) -> jax.Array:
+    """The down projection of the sorted rows ``h`` (T·k,f), then the
+    inverse of :func:`_dispatch`, weighted: ``y[t] = sum_j top_vals[t, j]
+    · ys[inv[t·k + j]]``.  Backward gets the gates' gradient from ``h``
+    and the down projection's input gradient, so it needs neither the
+    expert outputs nor a token-major copy of them."""
+    t, k = top_vals.shape
+    ys = _grouped(h, w_down, group_sizes)
+    return jnp.sum(ys[inv].reshape(t, k, -1) * top_vals[..., None], axis=1)
+
+
+def _down_combine_fwd(h, w_down, group_sizes, top_vals, order, inv):
+    return (_down_combine(h, w_down, group_sizes, top_vals, order, inv),
+            (h, w_down, group_sizes, top_vals, order, inv))
+
+
+def _down_combine_bwd(res, g):
+    h, w_down, group_sizes, top_vals, order, inv = res
+    gs = g[order // top_vals.shape[1]]                       # (T·k, d)
+    vals = top_vals.reshape(-1)[order][:, None]              # (T·k, 1)
+    u = _grouped(gs, jnp.swapaxes(w_down, 1, 2), group_sizes)
+    d_vals = jnp.sum(u * h, axis=-1)[inv].reshape(top_vals.shape)
+    _, w_vjp = jax.vjp(lambda w: _grouped(h, w, group_sizes), w_down)
+    (d_w,) = w_vjp(gs * vals)
+    return u * vals, d_w, None, d_vals, None, None
+
+
+_down_combine.defvjp(_down_combine_fwd, _down_combine_bwd)
+
+
+def _experts_dropless(p: dict, x2d: jax.Array, top_idx: jax.Array,
+                      top_vals: jax.Array, activation: str) -> jax.Array:
+    """Each token through its own top-k experts only: the T·k assignments
+    sorted by expert, one grouped matmul per weight, un-sorted and
+    combined.  Returns y (T,d) in ``x2d``'s dtype."""
+    act = ACTIVATIONS[activation]
+    k = top_idx.shape[1]
+    e = p["w_gate"].shape[0]
+    with jax.named_scope("moe/dispatch"):
+        flat = top_idx.reshape(-1)                           # (T·k,)
+        order = jnp.argsort(flat, stable=True)               # sorted by expert
+        inv = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            flat[:, None] == jnp.arange(e, dtype=flat.dtype), axis=0,
+            dtype=jnp.int32)                                 # (E,)
+        xs = _dispatch(x2d, order, inv, k)                   # (T·k, d)
+    with jax.named_scope("moe/experts"):
+        h = act(_grouped(xs, p["w_gate"], group_sizes),
+                _grouped(xs, p["w_up"], group_sizes))        # (T·k, f)
+    with jax.named_scope("moe/combine"):
+        y = _down_combine(h, p["w_down"], group_sizes, top_vals, order, inv)
+    return y.astype(x2d.dtype)
+
+
+def _data_mesh():
+    """The context mesh when it splits the batch over ``"data"`` and over
+    no other axis (the elastic trainer's data-parallel step), else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("data", 1) == 1:
+        return None
+    return mesh if mesh.size == mesh.shape["data"] else None
+
+
+def _experts_dropless_sharded(p: dict, x: jax.Array, top_idx: jax.Array,
+                              top_vals: jax.Array,
+                              activation: str) -> jax.Array:
+    """:func:`_experts_dropless` on (B,S,·) inputs.  Under a data-parallel
+    context mesh each shard sorts, gathers and multiplies its own rows
+    (``shard_map``): a sort over the whole sharded T·k axis would be
+    replicated, and every chip would run the global batch's expert work.
+    The replicated weights' gradients are summed over ``"data"``."""
+    b, s, d = x.shape
+    k = top_idx.shape[-1]
+    experts = {n: p[n] for n in ("w_gate", "w_up", "w_down")}
+
+    def local(w, x, top_idx, top_vals):
+        bl = x.shape[0]
+        return _experts_dropless(
+            w, x.reshape(bl * s, d), top_idx.reshape(bl * s, k),
+            top_vals.reshape(bl * s, k), activation).reshape(bl, s, d)
+
+    mesh = _data_mesh()
+    if mesh is not None:
+        local = jax.shard_map(local, mesh=mesh,
+                              in_specs=(P(), P("data"), P("data"),
+                                        P("data")),
+                              out_specs=P("data"))
+    return local(experts, x, top_idx.reshape(b, s, k),
+                 top_vals.reshape(b, s, k))
+
+
 def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, *,
-              strategy: str = "dense") -> tuple[jax.Array, jax.Array]:
+              strategy: str = "dropless") -> tuple[jax.Array, jax.Array]:
     """x: (B,S,d) -> (y, aux_loss)."""
     moe = cfg.moe
     assert moe is not None
     b, s, d = x.shape
     x2d = x.reshape(b * s, d)
-    gates, aux = _route(p, x2d, moe)
-    if strategy == "capacity":
-        y = _experts_capacity(p, x2d, gates, moe, cfg.mlp_activation)
+    with jax.named_scope("moe/route"):
+        top_idx, top_vals, aux = _route(p, x2d, moe)
+    if strategy == "dropless":
+        y = _experts_dropless_sharded(p, x, top_idx, top_vals,
+                                      cfg.mlp_activation).reshape(b * s, d)
+    elif strategy == "dense":
+        y = _experts_dense(p, x2d, _gates(top_idx, top_vals, moe.n_experts),
+                           cfg.mlp_activation)
+    elif strategy == "capacity":
+        y = _experts_capacity(p, x2d,
+                              _gates(top_idx, top_vals, moe.n_experts),
+                              moe, cfg.mlp_activation)
     else:
-        y = _experts_dense(p, x2d, gates, cfg.mlp_activation)
+        raise ValueError(f"unknown moe strategy {strategy!r}")
     if moe.n_shared:
         y = y + mlp_apply(p["shared"], x2d, cfg.mlp_activation)
     return y.reshape(b, s, d), aux

@@ -81,7 +81,7 @@ def block_defs(cfg: ArchConfig, spec: LayerSpec, model_shards: int,
 
 def apply_block(cfg: ArchConfig, spec: LayerSpec, p: dict, x: jax.Array, *,
                 memory: Optional[jax.Array] = None,
-                moe_strategy: str = "dense",
+                moe_strategy: str = "dropless",
                 long_serving: bool = False) -> tuple[jax.Array, jax.Array]:
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, p["mixer_norm"], cfg.norm_eps)

@@ -1,6 +1,7 @@
 """``chip_smoke.py`` on the CPU: it refuses to run without a TPU, and its
 one-chip path (hole trace -> ControlLoop -> LiveBackend -> ElasticTrainer,
-with parks and resumes) passes its own checks at a ``reduced()`` size."""
+with parks and resumes) and its MoE gradient check pass their own checks
+at a ``reduced()`` size."""
 import importlib.util
 import os
 
@@ -34,4 +35,13 @@ def test_chip_smoke_one_chip_checks_pass_at_reduced_size(chip_smoke,
     monkeypatch.setattr(chip_smoke, "memory_stats", lambda device: {
         "bytes_in_use": 0, "peak_bytes_in_use": 0})
     checks = chip_smoke.one_chip()
+    assert checks and all(checks.values()), checks
+
+
+def test_chip_smoke_moe_grads_check_passes_at_reduced_size(chip_smoke,
+                                                           monkeypatch):
+    monkeypatch.setattr(chip_smoke, "moe_config",
+                        lambda: get_arch("granite-moe-3b-a800m").reduced())
+    monkeypatch.setattr(chip_smoke, "MOE_BATCH", (2, 64))
+    checks = chip_smoke.moe_grads()
     assert checks and all(checks.values()), checks

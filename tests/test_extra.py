@@ -56,6 +56,90 @@ def test_moe_capacity_drops_overflow_gracefully():
     assert float(jnp.mean(jnp.abs(yc))) >= 0.0
 
 
+@pytest.mark.parametrize("arch,skew", [
+    ("granite-moe-3b-a800m", False), ("granite-moe-3b-a800m", True),
+    ("deepseek-v2-lite-16b", False), ("jamba-v0.1-52b", True)])
+def test_moe_dropless_matches_dense(arch, skew):
+    """The dropless path is the dense path's function: output, aux loss and
+    the gradients of input, router and every expert weight, at the
+    published expert count and top-k (shared experts where the config has
+    them).  A skewed router leaves half the experts without rows and gives
+    expert 0 every token; no token is dropped either way."""
+    from repro.configs import get_arch
+    from repro.models import moe as M
+    from repro.models.layers import materialize
+    full = get_arch(arch)
+    cfg = dataclasses.replace(full.reduced(), moe=dataclasses.replace(
+        full.moe, d_expert=32))
+    e, k = cfg.moe.n_experts, cfg.moe.top_k
+    params = materialize(M.moe_defs(cfg), jax.random.key(1))
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(2, 24, cfg.d_model), jnp.float32)
+    if skew:
+        x = x * 0.5 + 1.0
+        bias = np.zeros(e, np.float32)
+        bias[0], bias[e // 2:] = 0.05, -0.05
+        params["router"] = params["router"] * 0.1 + bias
+    t = x.shape[0] * x.shape[1]
+    weights = jnp.asarray(rs.randn(*x.shape), jnp.float32)
+
+    def loss(strategy):
+        def f(p, x):
+            y, aux = M.moe_apply(p, x, cfg, strategy=strategy)
+            return jnp.sum(y * weights) + aux, (y, aux)
+        return jax.jit(jax.grad(f, argnums=(0, 1), has_aux=True))(params, x)
+
+    with jax.default_matmul_precision("highest"):
+        (gd, (yd, ad)), (gs, (ys, as_)) = loss("dense"), loss("dropless")
+        top_idx = jax.jit(lambda p, x2d: M._route(p, x2d, cfg.moe)[0])(
+            params, x.reshape(t, -1))
+    sizes = np.bincount(np.asarray(top_idx).reshape(-1), minlength=e)
+    assert sizes.sum() == t * k
+    if skew:
+        assert sizes[0] == t and not sizes[e // 2:].any()
+
+    def close(a, b):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(a))))
+
+    close(yd, ys)
+    assert abs(float(ad) - float(as_)) <= 1e-7
+    close(gd[1], gs[1])
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        close(gd[0][name], gs[0][name])
+    if cfg.moe.n_shared:
+        jax.tree.map(close, gd[0]["shared"], gs[0]["shared"])
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-3b-a800m",
+                                  "deepseek-v2-lite-16b"])
+def test_moe_model_at_bfloat16_keeps_the_residual_dtype(arch):
+    """With bfloat16 params and activations the dropless MoE returns
+    bfloat16, as the dense path does, so the layer scan's carry keeps its
+    dtype; the loss matches the dense path's to bfloat16 rounding and every
+    gradient is finite and in its param's dtype."""
+    from repro.configs import get_arch
+    from repro.models import moe as M
+    from repro.models.model_zoo import Model
+    cfg = get_arch(arch).reduced()
+    rs = np.random.RandomState(0)
+    batch = {k: jnp.asarray(rs.randint(0, cfg.vocab_size, (2, 32)),
+                            jnp.int32) for k in ("tokens", "labels")}
+    losses = {}
+    for strategy in ("dense", "dropless"):
+        model = Model(cfg, dtype=jnp.bfloat16, moe_strategy=strategy)
+        params = model.init(jax.random.key(0))
+        loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, batch)
+        losses[strategy] = float(loss)
+        for p, g in zip(jax.tree.leaves(params), jax.tree.leaves(grads)):
+            assert g.dtype == p.dtype and bool(jnp.all(jnp.isfinite(g)))
+    moe_p = next(jax.tree.map(lambda a: a[0], b["mlp"])
+                 for b in params["blocks"] if "router" in b["mlp"])
+    x = jnp.ones((1, 8, cfg.d_model), jnp.bfloat16)
+    assert M.moe_apply(moe_p, x, cfg)[0].dtype == jnp.bfloat16
+    assert abs(losses["dropless"] - losses["dense"]) < 0.02, losses
+
+
 def _run(mod, args, timeout=600):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
