@@ -17,9 +17,11 @@ into each layer, to time them and to put a profiler span around them:
 Set-up builds the trainer, takes the checked steps (``check_nodes``)
 and, where the traffic parks, parks and resumes once.  The window then runs the loop
 for ``seconds`` of wall time; the loop jumps over busy periods in no wall
-time, so the window is the job's elastic life made contiguous.  Steps that
-end after the close do not count.  After it the trainer's state is freed
-and the reference follows the checked steps (``bench/check.py``).
+time, so the window is the job's elastic life made contiguous.  The step
+that runs over the close counts towards the rate, with the time it took
+(``Recorder.overrun``); the per-layer readers see the steps that ended
+inside.  After it the trainer's state is freed and the reference follows
+the checked steps (``bench/check.py``).
 """
 from __future__ import annotations
 
@@ -40,9 +42,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, device, flops
+from bench import check, device
 from bench import trace as tracing
-from bench.reference import train as reference
+from bench.reference import module_for, train as reference
 from bench.traffic import holes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -109,39 +111,62 @@ def load_reader(metric: str) -> Callable:
     return mod.read
 
 
+def _as_stated(value, stated):
+    """The program's value in the form the file states it: a nested
+    dataclass as a dict of the keys stated (all where the file states
+    none), the layer pattern as a list of whole entries."""
+    if dataclasses.is_dataclass(value):
+        return {k: getattr(value, k) for k in (stated or
+                                               dataclasses.asdict(value))}
+    if isinstance(value, tuple):
+        return [_as_stated(v, None) for v in value]
+    return value
+
+
 def arch_config(cfg: Dict):
     """The program's ``ArchConfig`` for a configuration file, checked
-    against the numbers the file states."""
-    from repro.configs import get_arch
+    against every key of the file's ``arch`` (a field of the program's,
+    or one of the reference's own ``file_only`` parameters; any other is
+    refused), and against what its reference module
+    (``bench.reference.module_for``) computes.  ``changes`` may rebuild
+    ``moe``, ``mla``, ``ssm`` (dicts) and ``layer_pattern`` (a list of
+    ``{"mixer", "mlp"}``)."""
+    from repro.configs import (LayerSpec, MLAConfig, MoEConfig, SSMConfig,
+                               get_arch)
     base = get_arch(cfg["registry"])
     changes = dict(cfg["changes"])
-    if "moe" in changes:
-        changes["moe"] = dataclasses.replace(base.moe, **changes["moe"])
+    for key, kind in (("moe", MoEConfig), ("mla", MLAConfig),
+                      ("ssm", SSMConfig)):
+        if changes.get(key) is not None:
+            old = getattr(base, key)
+            changes[key] = (kind(**changes[key]) if old is None else
+                            dataclasses.replace(old, **changes[key]))
+    if "layer_pattern" in changes:
+        changes["layer_pattern"] = tuple(LayerSpec(**s)
+                                         for s in changes["layer_pattern"])
     arch = dataclasses.replace(base, **changes)
     a = cfg["arch"]
-    want = {k: a[k] for k in ("n_layers", "d_model", "n_heads", "n_kv_heads",
-                              "head_dim", "d_ff", "vocab_size", "rope_theta",
-                              "norm_eps", "tie_embeddings", "mlp_activation")}
-    got = {k: getattr(arch, k) for k in want}
-    moe = a.get("moe")
-    want["moe"] = moe
-    got["moe"] = None if arch.moe is None else {
-        k: getattr(arch.moe, k) for k in (moe or {})}
-    want["plain"] = True
-    got["plain"] = (len(arch.layer_pattern) == 1
-                    and arch.layer_pattern[0].mixer == "attn"
-                    and arch.mla is None and arch.ssm is None
-                    and arch.encoder is None and arch.frontend == "none"
-                    and not (arch.sliding_window or arch.post_norms
-                             or arch.qk_norm or arch.scale_embeddings
-                             or arch.attn_logit_softcap
-                             or arch.final_logit_softcap)
-                    and (arch.query_scale or arch.head_dim ** -0.5)
-                    == a.get("attention_multiplier", arch.head_dim ** -0.5))
-    if got != want:
-        diff = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
+    ref = module_for(cfg)
+    # a key the program has no field for is one of the reference's own
+    # parameters (granite's multipliers), at the value the program runs
+    own = ref.file_only(arch)
+    fields = {f.name for f in dataclasses.fields(arch)}
+    unknown = sorted(set(a) - fields - set(own))
+    if unknown:
+        raise ValueError(f"{cfg['name']}: the file states what neither the "
+                         f"program's ArchConfig nor the reference "
+                         f"{ref.__name__} has: {', '.join(unknown)}")
+    got = {k: _as_stated(getattr(arch, k), v) if k in fields else own[k]
+           for k, v in a.items()}
+    if got != a:
+        diff = {k: (got[k], a[k]) for k in a if got[k] != a[k]}
         raise ValueError(f"{cfg['name']}: the program's {cfg['registry']} "
                          f"is not what the file states: {diff}")
+    missing = ref.unmodelled(arch, a)
+    if missing:
+        raise ValueError(f"{cfg['name']}: the program's {cfg['registry']} "
+                         f"has what the reference {ref.__name__} does not "
+                         f"compute: {', '.join(missing)}")
     return arch
 
 
@@ -198,6 +223,8 @@ class Recorder:
         self.compiles = 0
         self.traces = 0
         self.interval_steps: List[int] = []
+        # the step begun in the window that ended after its close
+        self.overrun: Optional[Step] = None
 
     @property
     def open(self) -> bool:
@@ -209,6 +236,11 @@ class Recorder:
 
     def stop(self) -> None:
         self.deadline = None
+
+    def end(self) -> float:
+        """The end of the window's work: the close, or the end of the
+        step that ran over it."""
+        return self.overrun.t1 if self.overrun else self.deadline
 
     def check_deadline(self) -> None:
         if self.open and time.perf_counter() >= self.deadline:
@@ -270,11 +302,12 @@ class Recorder:
         self.attempted += 1
         if not math.isfinite(m.loss):
             self.failed += 1
+        step = Step(t0, t1, n, m.samples, m.step_time_s, m.loss,
+                    self.pending == n)
         if t1 > self.deadline:
+            self.overrun = step
             raise WindowClosed
-        first = self.pending == n
-        self.run.steps.append(Step(t0, t1, n, m.samples, m.step_time_s,
-                                   m.loss, first))
+        self.run.steps.append(step)
         if self.interval_steps:
             self.interval_steps[-1] += 1
         self.pending = None
@@ -496,7 +529,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
     data = RunData(seconds=seconds, chips=cell.chips,
                    seq_len=cfg["train"]["seq_len"],
-                   flops_per_token=flops.flops_per_token(
+                   flops_per_token=module_for(cfg).flops_per_token(
                        cfg["arch"], cfg["train"]["seq_len"]),
                    peak_flops=peak["bf16_flops_per_s"])
     rec = Recorder(data, lambda: memory_stats(devices[0])["bytes_in_use"])
@@ -556,6 +589,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             raise RuntimeError("the hole trace ended before the window "
                                "closed; raise the traffic's 'passes'")
         closed = time.perf_counter()
+        end = rec.end()
         rec.stop()
     counting[0] = False
     if trace:
@@ -583,6 +617,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
     # ---- the result ----
     steps = data.steps
+    # the rate: every step begun in the window, over the time from its
+    # opening to the end of the last of them (the close, where none ran
+    # over it), so it moves by less than a whole step
+    done = steps + ([rec.overrun] if rec.overrun else [])
+    tokens_per_s = sum(s.rows for s in done) * data.seq_len / (
+        end - rec.opened)
+    walls = sorted(range(len(steps)), key=lambda i: steps[i].t0 - steps[i].t1)
+    gaps = [steps[i + 1].t0 - steps[i].t1 for i in range(len(steps) - 1)]
     counts = {
         "window_s": closed - rec.opened,
         "steps": len(steps), "pool_events": rec.pool_events,
@@ -596,7 +638,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         "compilations_in_window": rec.compiles,
         "traces_in_window": rec.traces,
         "steps_per_interval": rec.interval_steps[:40],
-        "tokens_per_s": sum(st.rows for st in steps) * data.seq_len / seconds,
+        "tokens_per_s": tokens_per_s,
+        "rate_s": end - rec.opened,
+        # where a slow run lost its time: the three longest steps as
+        # [index, start in the window s, wall ms, step_time_s ms], and
+        # the longest gap between two steps
+        "slowest_steps": [[i, steps[i].t0 - rec.opened,
+                           1e3 * (steps[i].t1 - steps[i].t0),
+                           1e3 * steps[i].step_time_s] for i in walls[:3]],
+        "longest_gap_ms": 1e3 * max(gaps, default=0.0),
         "releases_s": data.releases,
         "not_compared": {k: v for k, v in values.items()
                          if k not in table},
@@ -606,8 +656,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     print("window: " + json.dumps(counts), flush=True)
     metrics: Dict[str, Dict] = {}
     if not trace:
-        tokens = sum(s.rows for s in steps) * data.seq_len
-        e2e = {"train_tokens_per_s": tokens / seconds,
+        e2e = {"train_tokens_per_s": tokens_per_s,
                "release_s": (float(np.mean(data.releases))
                              if data.releases else None),
                "setup_s": setup_s}

@@ -1,9 +1,10 @@
 """The step's share of the chips' peak (%): the operations the window's
-steps require (``bench/flops.py``; no recomputation, only routed experts)
-over the sum of each step's ``step_time_s`` times the chips it ran on,
-times one chip's peak (``bench/peaks.json``).  The first step at each new
-node count is left out: it also waits for the copies of the rescale
-before it."""
+steps require (the ``flops_per_token`` of the configuration's reference
+module: ``bench/flops.py`` for ``lm``; no recomputation, only routed
+experts) over the sum of each step's ``step_time_s`` times the chips it
+ran on, times one chip's peak (``bench/peaks.json``).  The first step at
+each new node count is left out: it also waits for the copies of the
+rescale before it."""
 
 
 def read(run):

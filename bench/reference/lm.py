@@ -30,16 +30,25 @@ below the single bfloat16 pass in which the program's float32 matmuls run
 on the TPU.  Statistics of norms, softmaxes and the loss stay in
 float32.  To fit a chip beside the optimizer state, each layer is
 rematerialised and attention and the loss head run over blocks of rows.
+
+The rest of the reference contract (``bench/reference/__init__.py``):
+``flops_per_token`` is ``bench/flops.py``'s count, ``file_only`` gives
+the multipliers above as the program runs them, ``unmodelled`` names
+what of the program's model this file does not compute, and ``tiny``
+cuts a configuration to the CPU tests' size.
 """
 from __future__ import annotations
 
+import copy
 import math
 import zlib
 from functools import partial
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench.flops import flops_per_token  # noqa: F401  (the contract's)
 
 HIGHEST = jax.lax.Precision.HIGHEST
 DEFAULT = jax.lax.Precision.DEFAULT
@@ -116,7 +125,8 @@ def fp8(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _mm(prec):
+def matmul(prec):
+    """``einsum(spec, a, b)`` in the arithmetic ``prec`` names."""
     if prec == FP8:
         return lambda spec, a, b: jnp.einsum(spec, fp8(a), fp8(b),
                                              precision=DEFAULT)
@@ -141,7 +151,7 @@ def rope(x, theta):
 
 
 def attention(a, p, l, h, dt, prec):
-    mm = _mm(prec)
+    mm = matmul(prec)
     b = "['blocks'][0]['mixer']"
     B, S, _ = h.shape
     H, KV, D = a["n_heads"], a["n_kv_heads"], a["head_dim"]
@@ -175,7 +185,7 @@ def swiglu(x, w_gate, w_up, w_down, mm):
 
 def mlp(a, p, l, h, dt, prec):
     """Returns (output, load-balance loss)."""
-    mm = _mm(prec)
+    mm = matmul(prec)
     b = "['blocks'][0]['mlp']"
     B, S, d = h.shape
     x = h.reshape(B * S, d)
@@ -209,7 +219,7 @@ def loss(a: Dict, p: Params, tokens: jax.Array, *, dt=jnp.float32,
          prec=HIGHEST) -> jax.Array:
     """Mean next-token cross-entropy of ``tokens`` (B, S), plus the
     load-balance loss of the MoE layers."""
-    mm = _mm(prec)
+    mm = matmul(prec)
     eps = a["norm_eps"]
     res = a.get("residual_multiplier", 1.0)
     x = (p["['embed']"][tokens] * a.get("embedding_multiplier", 1.0)
@@ -242,3 +252,73 @@ def loss(a: Dict, p: Params, tokens: jax.Array, *, dt=jnp.float32,
                    tokens[:, i + 1:min(i + ROW_BLOCK, S - 1) + 1])
                 for i in range(0, S - 1, ROW_BLOCK))
     return total / (B * (S - 1)) + aux
+
+
+# ---------------------------------------------------------------------------
+# the rest of the contract
+# ---------------------------------------------------------------------------
+
+
+def file_only(arch) -> Dict[str, float]:
+    """The file's keys that the program's ``ArchConfig`` has no field of
+    that name for, which this reference applies, at the values the
+    program runs: no multiplier, and its own attention scale."""
+    return {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
+            "logits_scaling": 1.0,
+            "attention_multiplier": arch.query_scale or arch.head_dim ** -0.5}
+
+
+def unmodelled(arch, a: Dict) -> List[str]:
+    """The features of the program's ``ArchConfig`` that this reference
+    does not compute, each by its name: anything but one attention layer
+    repeated, with a SwiGLU MLP of width ``d_ff`` or routed experts
+    alone, full causal attention at the scale the file states (or
+    ``head_dim ** -0.5`` where it states none), plain pre-norms and
+    unscaled embeddings."""
+    pattern = arch.layer_pattern
+    scale = arch.head_dim ** -0.5
+    terms = {
+        "layer_pattern": not (len(pattern) == 1
+                              and pattern[0].mixer == "attn"),
+        "mla": arch.mla is not None,
+        "ssm": arch.ssm is not None,
+        "encoder": arch.encoder is not None,
+        "frontend": arch.frontend != "none",
+        "sliding_window": bool(arch.sliding_window),
+        "post_norms": arch.post_norms,
+        "qk_norm": arch.qk_norm,
+        "scale_embeddings": arch.scale_embeddings,
+        "attn_logit_softcap": bool(arch.attn_logit_softcap),
+        "final_logit_softcap": bool(arch.final_logit_softcap),
+        "query_scale": (arch.query_scale or scale)
+        != a.get("attention_multiplier", scale),
+        # computed as the file's ``moe`` says, not as the pattern says
+        "mlp_kind": any(s.mlp != ("moe" if a.get("moe") else "dense")
+                        for s in pattern),
+        "mlp_activation": arch.mlp_activation != "swiglu",
+        "dense_d_ff": arch.dense_d_ff not in (0, arch.d_ff),
+        "moe.n_shared": bool(arch.moe and arch.moe.n_shared),
+    }
+    return [name for name, missing in terms.items() if missing]
+
+
+TINY_SEQ_LEN = 64
+TINY_WIDTHS = {"d_model": 128, "n_heads": 4, "n_kv_heads": 2, "head_dim": 32,
+               "d_ff": 256, "vocab_size": 512}
+TINY_MOE = {"n_experts": 4, "top_k": 2, "d_expert": 64}
+
+
+def tiny(cfg: Dict) -> Dict:
+    """A configuration file's dict at ``reduced()``-like widths, the
+    structure (pattern, MoE routing, untied head) kept."""
+    cfg = copy.deepcopy(cfg)
+    widths = dict(TINY_WIDTHS)
+    if cfg["arch"].get("moe"):
+        widths["d_ff"] = TINY_MOE["d_expert"]
+        cfg["changes"]["moe"] = dict(TINY_MOE)
+        cfg["arch"]["moe"].update(TINY_MOE)
+        cfg["arch"]["attention_multiplier"] = TINY_WIDTHS["head_dim"] ** -0.5
+    cfg["changes"].update(widths)
+    cfg["arch"].update(widths)
+    cfg["train"]["seq_len"] = TINY_SEQ_LEN
+    return cfg

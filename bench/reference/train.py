@@ -1,6 +1,7 @@
 """The reference's first training steps, and what the check reads of them.
 
-``train`` starts from the weights the seed gives (``lm.init``), takes one
+``train`` starts from the weights the seed gives (``init`` of the
+configuration's reference module, ``bench.reference.module_for``), takes one
 AdamW step per entry of ``node_counts`` on the next ``n * per_node_batch``
 rows of the stream, and returns the numbers the check compares:
 
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import check
-from bench.reference import data, lm, optim
+from bench.reference import data, lm, module_for, optim
 
 
 def leaf_norms(tree: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -38,10 +39,11 @@ def make_step(cfg: Dict, dt=jnp.float32, prec=lm.HIGHEST):
     (params, mu, nu, loss, clipped gradient norms, clipped gradient at
     the flat indices idx)``; params and moments are donated."""
     a, t = cfg["arch"], cfg["train"]
+    ref = module_for(cfg)
 
     def one(params, mu, nu, step, tokens, n_nodes, idx):
         value, grads = jax.value_and_grad(
-            lambda p: lm.loss(a, p, tokens, dt=dt, prec=prec))(params)
+            lambda p: ref.loss(a, p, tokens, dt=dt, prec=prec))(params)
         params, mu, nu, clipped = optim.update(t, grads, params, mu, nu,
                                                step, n_nodes)
         sample = {k: clipped[k][jnp.unravel_index(i, clipped[k].shape)]
@@ -55,7 +57,7 @@ def train(cfg: Dict, seed: int, node_counts: Sequence[int], *,
           dt=jnp.float32, prec=lm.HIGHEST,
           rows_of: Optional[Callable] = None) -> Dict:
     a, t = cfg["arch"], cfg["train"]
-    params = lm.init(a, seed)
+    params = module_for(cfg).init(a, seed)
     mu = {k: jnp.zeros_like(v) for k, v in params.items()}
     nu = {k: jnp.zeros_like(v) for k, v in params.items()}
     idx = check.sample_indices(seed, {k: v.shape for k, v in params.items()})
@@ -75,14 +77,14 @@ def train(cfg: Dict, seed: int, node_counts: Sequence[int], *,
             grad = {k: float(v) for k, v in gn.items()}
             sample = {k: np.asarray(v) for k, v in gs.items()}
     del mu, nu
-    change = change_norms(a, seed, params)
+    change = change_norms(cfg, seed, params)
     return {"losses": losses, "grad": grad, "grad_sample": sample,
             "change": change}
 
 
-def change_norms(a: Dict, seed: int, params: Dict) -> Dict[str, float]:
+def change_norms(cfg: Dict, seed: int, params: Dict) -> Dict[str, float]:
     """Per leaf, ``|params - init(seed)|``."""
-    start = lm.init(a, seed)
+    start = module_for(cfg).init(cfg["arch"], seed)
     out = jax.jit(lambda p, s: leaf_norms(
         {k: p[k] - s[k] for k in p}))(params, start)
     return {k: float(v) for k, v in out.items()}

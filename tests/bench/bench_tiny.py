@@ -1,6 +1,5 @@
 """Shared helpers of the benchmark's CPU tests: the repository's root on
 the import path, and the benchmark's cells cut to a size a test holds."""
-import copy
 import math
 import os
 import sys
@@ -12,11 +11,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
         sys.path.insert(0, _p)
 
 from bench import device, harness  # noqa: E402
-
-SEQ_LEN = 64
-WIDTHS = {"d_model": 128, "n_heads": 4, "n_kv_heads": 2, "head_dim": 32,
-          "d_ff": 256, "vocab_size": 512}
-MOE = {"n_experts": 4, "top_k": 2, "d_expert": 64}
+from bench.reference import module_for  # noqa: E402
 
 
 def on_cpu(monkeypatch) -> None:
@@ -30,19 +25,9 @@ def on_cpu(monkeypatch) -> None:
 
 
 def tiny_config(cfg: dict) -> dict:
-    """A configuration file's dict at ``reduced()``-like widths, the
-    structure (pattern, MoE routing, untied head) kept."""
-    cfg = copy.deepcopy(cfg)
-    widths = dict(WIDTHS)
-    if cfg["arch"].get("moe"):
-        widths["d_ff"] = MOE["d_expert"]
-        cfg["changes"]["moe"] = dict(MOE)
-        cfg["arch"]["moe"].update(MOE)
-        cfg["arch"]["attention_multiplier"] = WIDTHS["head_dim"] ** -0.5
-    cfg["changes"].update(widths)
-    cfg["arch"].update(widths)
-    cfg["train"]["seq_len"] = SEQ_LEN
-    return cfg
+    """A configuration file's dict at the size its reference module's
+    ``tiny`` gives, the structure kept."""
+    return module_for(cfg).tiny(cfg)
 
 
 def tiny_cell(name: str) -> "harness.Cell":

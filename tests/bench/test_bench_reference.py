@@ -1,7 +1,10 @@
 """The benchmark's plain reference agrees with the program at small
 widths on the CPU: the same initial weights, the same loss, the same
-AdamW steps (``make_train_step``), for both configurations."""
+AdamW steps (``make_train_step``), for every cell of ``BENCHMARK.json``,
+each against the reference module its configuration names."""
+import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -10,9 +13,10 @@ import pytest
 
 import bench_tiny
 from bench import harness
-from bench.reference import data, lm, train as reference
+from bench.reference import data, module_for, train as reference
 
-CONFIGS = ["yi-6b-1l.holes-short", "granite-moe-3b-4l.hole-long"]
+with open(os.path.join(bench_tiny.ROOT, "BENCHMARK.json")) as f:
+    CELLS = [w["name"] for w in json.load(f)["workloads"]]
 SEED = 2**31 + 17
 
 
@@ -26,12 +30,13 @@ def _flat(tree):
             for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CELLS)
 def test_reference_init_equals_the_programs(name):
     cfg = bench_tiny.tiny_cell(name).config
     model = _program(cfg)
     prog = _flat(model.init(jax.random.key(SEED)))
-    ref = {k: np.asarray(v) for k, v in lm.init(cfg["arch"], SEED).items()}
+    ref = {k: np.asarray(v)
+           for k, v in module_for(cfg).init(cfg["arch"], SEED).items()}
     assert prog.keys() == ref.keys()
     # the same draws of the same generator; the reference makes them in
     # one jitted call, where the multiply by the scale may round once
@@ -41,7 +46,7 @@ def test_reference_init_equals_the_programs(name):
                                    err_msg=k)
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CELLS)
 def test_reference_loss_matches_model_loss(name):
     cfg = bench_tiny.tiny_cell(name).config
     a, t = cfg["arch"], cfg["train"]
@@ -50,13 +55,14 @@ def test_reference_loss_matches_model_loss(name):
     tokens = data.rows(SEED, a["vocab_size"], t["seq_len"], 0, 3)
     want = float(model.loss(params, {"tokens": jnp.asarray(tokens),
                                      "labels": jnp.asarray(tokens)}))
-    got = float(lm.loss(a, lm.init(a, SEED), jnp.asarray(tokens)))
+    ref = module_for(cfg)
+    got = float(ref.loss(a, ref.init(a, SEED), jnp.asarray(tokens)))
     # float32 sums of ~200 terms in another order: a few ulp of a loss
     # near ln(512) = 6.2, far below the 1e-3 a wrong mask or routing moves
     assert got == pytest.approx(want, abs=1e-5)
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CELLS)
 def test_reference_rows_are_the_pipelines(name):
     from repro.data import DataConfig, TokenPipeline
     cfg = bench_tiny.tiny_cell(name).config
@@ -71,7 +77,7 @@ def test_reference_rows_are_the_pipelines(name):
         second["tokens"], data.rows(SEED, a["vocab_size"], t["seq_len"], 2, 4))
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CELLS)
 def test_reference_steps_match_make_train_step(name):
     """Three AdamW steps of the program's jitted step against the
     reference's, at one node: losses, the first clipped gradient's leaf
