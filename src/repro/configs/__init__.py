@@ -5,8 +5,10 @@ from repro.configs.base import (
     LayerSpec,
     MLAConfig,
     MoEConfig,
+    RoutingConfig,
     SHAPES,
     SSMConfig,
+    YarnConfig,
 )
 from repro.configs.registry import ARCHS, applicable_shapes, get_arch, get_shape
 
@@ -17,8 +19,10 @@ __all__ = [
     "LayerSpec",
     "MLAConfig",
     "MoEConfig",
+    "RoutingConfig",
     "SHAPES",
     "SSMConfig",
+    "YarnConfig",
     "ARCHS",
     "applicable_shapes",
     "get_arch",

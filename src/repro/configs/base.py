@@ -33,6 +33,37 @@ class MoEConfig:
 
 
 @dataclass(frozen=True)
+class RoutingConfig:
+    """How a MoE layer's router gates, and which routed experts it holds.
+
+    The router scores ``n_routed`` experts (0: ``moe.n_experts``); the
+    layer holds ``moe.n_experts`` of them, ids ``first_held`` onwards: one
+    chip's share of an expert-parallel deployment, or all of them.
+    """
+
+    n_routed: int = 0
+    first_held: int = 0
+    # top-k gates renormalised to sum to 1 (false: the softmax's own)
+    norm_topk: bool = True
+    # load-balance loss per sequence, averaged (DeepSeek's MoEGate), or
+    # over the whole batch (Switch)
+    seq_aux: bool = False
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2 applies it
+    (``DeepseekV2YarnRotaryEmbedding``)."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class MLAConfig:
     """DeepSeek-V2 multi-head latent attention."""
 
@@ -105,6 +136,7 @@ class ArchConfig:
 
     # attention knobs
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None    # latent attention's rope scaling
     attn_logit_softcap: float = 0.0      # 0 => disabled (gemma2: 50)
     final_logit_softcap: float = 0.0     # 0 => disabled (gemma2: 30)
     sliding_window: int = 0              # 0 => full attention for 'swa' none
@@ -115,6 +147,7 @@ class ArchConfig:
 
     # optional sub-modules
     moe: Optional[MoEConfig] = None
+    routing: RoutingConfig = RoutingConfig()
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
@@ -136,6 +169,11 @@ class ArchConfig:
             f"pattern length {len(self.layer_pattern)}"
         )
         return self.n_layers // len(self.layer_pattern)
+
+    @property
+    def n_routed(self) -> int:
+        """The MoE router's width: every expert, held here or not."""
+        return self.routing.n_routed or self.moe.n_experts
 
     @property
     def is_encdec(self) -> bool:
@@ -178,6 +216,18 @@ class ArchConfig:
                 d_expert=min(self.moe.d_expert, 128),
                 n_shared=min(self.moe.n_shared, 1),
             )
+            if self.n_routed > self.moe.n_experts:
+                # a held share stays a share: at most 2 held of 8
+                routed = min(self.n_routed, 8)
+                small["moe"] = dataclasses.replace(
+                    small["moe"], n_experts=min(small["moe"].n_experts, 2))
+                small["routing"] = dataclasses.replace(
+                    self.routing, n_routed=routed, first_held=min(
+                        self.routing.first_held,
+                        routed - small["moe"].n_experts))
+            else:
+                small["routing"] = dataclasses.replace(self.routing,
+                                                       n_routed=0)
         if self.mla is not None:
             small["mla"] = dataclasses.replace(
                 self.mla,

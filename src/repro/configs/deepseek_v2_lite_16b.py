@@ -1,11 +1,21 @@
 """DeepSeek-V2-Lite 16B — MoE with multi-head latent attention (MLA).
-[arXiv:2405.04434]
+[arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite config.json]
 
-MLA with kv_lora_rank=512; 2 shared + 64 routed experts, top-6
-(d_expert=1408). First layer uses a dense MLP (as in the released model);
-remaining 26 layers are MoE.
+MLA with kv_lora_rank=512, no query LoRA, 128 + 64 (rope) QK and 128 V
+head dims; the rope is YaRN-scaled (factor 40 over an original 4096
+positions, beta 32 / 1, mscale = mscale_all_dim = 0.707), and the softmax
+scale is ``192 ** -0.5 * m ** 2`` with ``m = 0.1 * 0.707 * ln 40 + 1``.
+The first layer has a dense SwiGLU MLP of width 10944; the other 26 are
+MoE: a softmax router over 64 routed experts (``routing.n_routed``), the
+top-6 gates taken as the softmax gives them (``norm_topk=False``), 2
+shared experts (one SwiGLU of width 2 x 1408), and a sequence-wise
+load-balance loss (``seq_aux``, coefficient 0.001).  All 64 experts are
+held here; a configuration that holds one chip's share of them sets
+``moe.n_experts`` to the experts held and ``routing.first_held`` to the
+first one's id.
 """
-from repro.configs.base import ArchConfig, LayerSpec, MLAConfig, MoEConfig
+from repro.configs.base import (ArchConfig, LayerSpec, MLAConfig, MoEConfig,
+                                RoutingConfig, YarnConfig)
 
 _PATTERN = (LayerSpec(mixer="attn", mlp="dense"),) + tuple(
     LayerSpec(mixer="attn", mlp="moe") for _ in range(26)
@@ -25,7 +35,12 @@ CONFIG = ArchConfig(
     vocab_size=102400,
     layer_pattern=_PATTERN,
     mlp_activation="swiglu",
-    moe=MoEConfig(n_experts=64, top_k=6, d_expert=1408, n_shared=2),
+    yarn=YarnConfig(factor=40.0, original_max_position_embeddings=4096,
+                    beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                    mscale_all_dim=0.707),
+    moe=MoEConfig(n_experts=64, top_k=6, d_expert=1408, n_shared=2,
+                  router_aux_coef=0.001),
+    routing=RoutingConfig(n_routed=64, norm_topk=False, seq_aux=True),
     mla=MLAConfig(
         kv_lora_rank=512, q_lora_rank=0,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,

@@ -12,6 +12,7 @@ This guarantees params / specs never drift apart structurally.
 from __future__ import annotations
 
 import dataclasses
+import math
 import zlib
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
@@ -132,17 +133,54 @@ ACTIVATIONS = {"swiglu": swiglu, "geglu": geglu}
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies of the rotary pairs; with ``yarn`` (a
+    ``YarnConfig``) DeepSeek-V2's blend: frequencies past the ramp divided
+    by ``factor``, those before it kept, linear over pairs ``low..high``."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn is None:
+        return freqs
+
+    def dim(rotations):     # the pair that turns this often in the window
+        return head_dim * math.log(yarn.original_max_position_embeddings / (
+            rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_scale(qk_dim: int, yarn=None) -> float:
+    """The attention logits' scale: ``qk_dim ** -0.5``, times ``m ** 2``
+    with ``m = yarn_mscale(factor, mscale_all_dim)`` under YaRN."""
+    scale = qk_dim ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  Under
+    ``yarn`` cos and sin are scaled by ``mscale(mscale) / mscale(all_dim)``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    freqs = rope_freqs(d, theta, yarn)                 # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     ang = ang[..., None, :]                            # (..., S, 1, D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

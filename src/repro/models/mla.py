@@ -1,7 +1,9 @@
 """DeepSeek-V2 Multi-head Latent Attention (MLA). [arXiv:2405.04434]
 
 Keys/values are compressed into a per-token latent ``c_kv`` of rank
-``kv_lora_rank`` plus a single shared RoPE key.  The decode path uses the
+``kv_lora_rank`` plus a single shared RoPE key.  The rope is YaRN-scaled
+where the configuration says (``cfg.yarn``), and the logits are scaled by
+``layers.softmax_scale``: ``qk ** -0.5``, times ``m ** 2`` under YaRN.  The decode path uses the
 *absorbed* formulation: query projections are folded through ``w_uk`` /
 ``w_uv`` so the KV cache stores only ``(rank + rope_dim)`` floats per token
 — this is the mechanism that makes MLA serve long contexts cheaply, and is
@@ -16,7 +18,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.attention import NEG_INF, attend_blockwise, attend_direct, \
     BLOCKWISE_THRESHOLD
-from repro.models.layers import ParamDef, apply_rope, dense_def, rms_norm
+from repro.models.layers import (ParamDef, apply_rope, dense_def, rms_norm,
+                                 softmax_scale)
 
 
 def mla_defs(cfg: ArchConfig, model_shards: int = 1, dtype=jnp.float32) -> dict:
@@ -49,33 +52,37 @@ def mla_apply(p: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     nope, rope_d, vd = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
     qk = nope + rope_d
 
-    q = (x @ p["wq"]).reshape(b, s, h, qk)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
     pos = jnp.arange(s)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    with jax.named_scope("mla/q"):
+        q = (x @ p["wq"]).reshape(b, s, h, qk)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        q_rope = apply_rope(q_rope, pos, cfg.rope_theta, cfg.yarn)
+        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
 
-    c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope((x @ p["w_kr"])[:, :, None, :], pos, cfg.rope_theta)
-    k_nope = (c_kv @ p["w_uk"]).reshape(b, s, h, nope)
-    v = (c_kv @ p["w_uv"]).reshape(b, s, h, vd)
-
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, rope_d))],
-                        axis=-1)
-    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope("mla/kv_latent"):
+        c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
+        k_rope = apply_rope((x @ p["w_kr"])[:, :, None, :], pos,
+                            cfg.rope_theta, cfg.yarn)
+        k_nope = (c_kv @ p["w_uk"]).reshape(b, s, h, nope)
+        v = (c_kv @ p["w_uv"]).reshape(b, s, h, vd)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, s, h, rope_d))], axis=-1)
 
     # KV == H heads, group size 1; pad V up to qk dim not needed — attend_*
     # contracts q·k on last dim and p·v on v's own dim, but our helpers
     # assume same head_dim.  Pad v to qk (zeros) and slice after.
-    qh = q_full.reshape(b, s, h, 1, qk)
-    v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - vd)))
-    kwargs = dict(q_pos=pos, k_pos=pos, causal=True, window=0,
-                  logit_cap=0.0, scale=qk ** -0.5)
-    if s > BLOCKWISE_THRESHOLD:
-        out = attend_blockwise(qh, k, v_pad, **kwargs)
-    else:
-        out = attend_direct(qh, k, v_pad, **kwargs)
-    out = out.reshape(b, s, h, qk)[..., :vd]
-    return out.reshape(b, s, h * vd) @ p["wo"]
+    with jax.named_scope("mla/attend"):
+        qh = q_full.reshape(b, s, h, 1, qk)
+        v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - vd)))
+        kwargs = dict(q_pos=pos, k_pos=pos, causal=True, window=0,
+                      logit_cap=0.0, scale=softmax_scale(qk, cfg.yarn))
+        if s > BLOCKWISE_THRESHOLD:
+            out = attend_blockwise(qh, k, v_pad, **kwargs)
+        else:
+            out = attend_direct(qh, k, v_pad, **kwargs)
+        out = out.reshape(b, s, h, qk)[..., :vd]
+    with jax.named_scope("mla/out"):
+        return out.reshape(b, s, h * vd) @ p["wo"]
 
 
 # ---------------------------------------------------------------------------
@@ -111,11 +118,12 @@ def mla_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     q = (x1 @ p["wq"]).reshape(b, h, qk)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     posv = jnp.full((1,), 1, jnp.int32) * pos
-    q_rope = apply_rope(q_rope[:, None], posv, cfg.rope_theta)[:, 0]
+    q_rope = apply_rope(q_rope[:, None], posv, cfg.rope_theta,
+                        cfg.yarn)[:, 0]
 
     c_new = rms_norm(x1 @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
     kr_new = apply_rope((x1 @ p["w_kr"])[:, None, None, :], posv,
-                        cfg.rope_theta)[:, 0, 0]
+                        cfg.rope_theta, cfg.yarn)[:, 0, 0]
 
     c_kv = jax.lax.dynamic_update_slice(
         cache["c_kv"], c_new[:, None, :].astype(cache["c_kv"].dtype), (0, pos, 0))
@@ -129,7 +137,8 @@ def mla_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
 
     s_lat = jnp.einsum("bhr,btr->bht", q_lat, c_kv.astype(q_lat.dtype))
     s_rope = jnp.einsum("bhd,btd->bht", q_rope, k_rope.astype(q_rope.dtype))
-    scores = (s_lat + s_rope).astype(jnp.float32) * qk ** -0.5
+    scores = (s_lat + s_rope).astype(jnp.float32) * softmax_scale(
+        qk, cfg.yarn)
     valid = jnp.arange(c_kv.shape[1]) <= pos
     scores = jnp.where(valid[None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

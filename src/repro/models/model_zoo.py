@@ -428,7 +428,7 @@ def _mla_prefill(cfg: ArchConfig, p: dict, h: jax.Array, cache_len: int):
     out = mla_mod.mla_apply(p, h, cfg)
     c_kv = rms_norm(h @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)
     k_rope = L.apply_rope((h @ p["w_kr"])[:, :, None, :], jnp.arange(s),
-                          cfg.rope_theta)[:, :, 0, :]
+                          cfg.rope_theta, cfg.yarn)[:, :, 0, :]
     if s < cache_len:
         c_kv = jnp.pad(c_kv, ((0, 0), (0, cache_len - s), (0, 0)))
         k_rope = jnp.pad(k_rope, ((0, 0), (0, cache_len - s), (0, 0)))

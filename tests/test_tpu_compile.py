@@ -159,3 +159,47 @@ def test_trainer_step_routes_granite_through_ragged_dots(topo, n_chips):
     per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert per_chip <= V5E_HBM_BYTES, per_chip
+
+
+def test_trainer_step_fits_v5e_for_the_deepseek_cell(topo):
+    """The benchmark's deepseek-v2-lite-5l (one chip's share of an 8-way
+    expert-parallel DeepSeek-V2-Lite: a dense and 4 MoE layers, 8 of 64
+    experts held, 12,800 vocabulary rows) at its 2 x 2048 batch: the
+    donated step fits one chip, the held experts run as ragged dots over
+    all 4096 x 6 routed rows in 8 groups, and its temporaries stay at the
+    3.45 GB it compiled to when the cell was added (5% of room)."""
+    import json
+    import os
+    import re
+    import sys
+    from repro.elastic.trainer import make_train_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import harness
+    with open(os.path.join(root, "bench", "configs",
+                           "deepseek-v2-lite-5l.json")) as f:
+        model = build_model(harness.arch_config(json.load(f)))
+    opt = AdamW()
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    step = make_train_step(model, opt, mesh, warmup_steps=2,
+                           total_steps=10_000)
+    batch = {k: jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+             for k in ("tokens", "labels")}
+    compiled = step.lower(params, opt_state, batch,
+                          jax.ShapeDtypeStruct((), jnp.float32)).compile()
+    ragged = set(re.findall(r"%ragged-dot[\w.-]* = f32\[([\d,]+)\]",
+                            compiled.as_text()))
+    assert ragged >= {"24576,1408", "24576,2048", "8,2048,1408",
+                      "8,1408,2048"}, ragged
+    mem = compiled.memory_analysis()
+    state = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves((params, opt_state)))
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes <= 1.05 * 3_452_616_704, \
+        mem.temp_size_in_bytes
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert per_chip <= V5E_HBM_BYTES, per_chip
