@@ -26,7 +26,8 @@ def _default_interpret() -> bool:
                                    "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     logit_cap: float = 0.0, scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     interpret = _default_interpret() if interpret is None else interpret
     return _fa.flash_attention(q, k, v, causal=causal, window=window,

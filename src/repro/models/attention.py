@@ -1,16 +1,17 @@
 """Grouped-query attention (GQA/MQA) with RoPE, sliding windows, logit
 soft-capping, cross-attention and decode-with-KV-cache.
 
-Two execution paths:
+Three execution paths, chosen by :func:`attend` from the platform and the
+shapes:
 
-* ``attend_direct`` — materializes the score matrix; used for short
-  sequences (training smoke, train_4k).
+* the Pallas flash-attention kernel (``repro.kernels.flash_attention``,
+  forward and backward) — self-attention on the TPU whose length tiles
+  into the kernel's blocks (:func:`flash_eligible`);
+* ``attend_direct`` — materializes the score matrix; everything else up
+  to ``BLOCKWISE_THRESHOLD`` (CPU, cross-attention, ragged lengths);
 * ``attend_blockwise`` — online-softmax over KV chunks (flash-attention
-  algorithm expressed in XLA via ``lax.scan``); used for long sequences so
-  the dry-run's compiled memory stays bounded.  The Pallas TPU kernel in
-  ``repro.kernels.flash_attention`` implements the same contraction with
-  explicit VMEM tiling; models default to the XLA path so that the dry-run
-  lowers on any backend.
+  algorithm expressed in XLA via ``lax.scan``); longer sequences, so the
+  dry-run's compiled memory stays bounded.
 """
 from __future__ import annotations
 
@@ -21,18 +22,16 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import ParamDef, apply_rope, dense_def, softcap
+from repro.kernels.flash_attention import BLOCKS
+from repro.kernels.ops import flash_attention
+from repro.models.layers import (ParamDef, apply_rope, data_mesh, dense_def,
+                                 softcap)
 
 NEG_INF = -2.0 ** 30  # large-but-finite; keeps bf16/fp32 softmax NaN-free
 
 BLOCKWISE_THRESHOLD = 8192   # switch to online-softmax path above this
 # Force the flash-style blockwise path at any length (perf variant knob).
 FORCE_BLOCKWISE = False
-# Use the Pallas TPU flash-attention kernel for self-attention (first-class
-# deployment path on TPU; interpret-mode on CPU). Set via
-# repro.models.attention.USE_PALLAS_KERNEL = True (see tests/test_kernels.py
-# for the model-level equivalence check).
-USE_PALLAS_KERNEL = False
 Q_BLOCK = 1024
 KV_BLOCK = 1024
 
@@ -97,8 +96,8 @@ def attend_blockwise(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      kv_block: int = KV_BLOCK) -> jax.Array:
     """Flash-attention contraction in XLA: scan over KV blocks with an
     online softmax, scanned over query blocks to bound live memory."""
-    B, Sq, KV, G, D = q.shape
-    Sk = k.shape[1]
+    B, Sq, KV, G, _ = q.shape
+    Sk, D = k.shape[1], v.shape[-1]
     nq = -(-Sq // q_block)
     nk = -(-Sk // kv_block)
     q_pad, kv_pad = nq * q_block - Sq, nk * kv_block - Sk
@@ -151,6 +150,67 @@ def attend_blockwise(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
+# The dispatch
+# ---------------------------------------------------------------------------
+
+
+def flash_eligible(platform: str, s: int, sk: int, cross: bool) -> bool:
+    """Whether attention takes the Pallas kernel: on the TPU, where it
+    compiles natively, for self-attention (``cross`` false) whose length
+    is a whole number of the kernel's smallest block."""
+    return (platform == "tpu" and not cross and s == sk
+            and s % BLOCKS[-1] == 0)
+
+
+def _flash(q, k, v, *, causal: bool, window: int, logit_cap: float,
+           scale: float) -> jax.Array:
+    """The kernel on (B,S,heads,D) operands.  Under a data-parallel
+    context mesh each shard runs it on its own rows (``shard_map``): the
+    partitioner cannot split a Mosaic call, and would gather the batch."""
+    def heads_first(x):
+        return x.transpose(0, 2, 1, 3)
+
+    def local(q, k, v):
+        return heads_first(flash_attention(
+            heads_first(q), heads_first(k), heads_first(v), causal=causal,
+            window=window, logit_cap=logit_cap, scale=scale))
+
+    mesh = data_mesh()
+    if mesh is not None:
+        local = jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"), check_vma=False)
+    return local(q, k, v)
+
+
+def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
+           window: int, logit_cap: float, scale: float,
+           q_pos: Optional[jax.Array] = None, cross: bool = False,
+           platform: Optional[str] = None) -> jax.Array:
+    """q: (B,S,H,Dqk); k: (B,Sk,KV,Dqk); v: (B,Sk,KV,Dv) -> (B,S,H,Dv).
+
+    The Pallas kernel where :func:`flash_eligible` holds for ``platform``
+    (``jax.default_backend()`` unless given; the kernel runs interpreted
+    off the TPU), else XLA: ``attend_direct``, or ``attend_blockwise``
+    above ``BLOCKWISE_THRESHOLD``.  ``q_pos`` defaults to ``arange(S)``;
+    explicit positions take the XLA path."""
+    B, S, H, Dqk = q.shape
+    Sk, KV = k.shape[1], k.shape[2]
+    platform = platform or jax.default_backend()
+    if flash_eligible(platform, S, Sk, cross or q_pos is not None):
+        return _flash(q, k, v, causal=causal, window=window,
+                      logit_cap=logit_cap, scale=scale)
+    kwargs = dict(q_pos=jnp.arange(S) if q_pos is None else q_pos,
+                  k_pos=jnp.arange(Sk), causal=causal, window=window,
+                  logit_cap=logit_cap, scale=scale)
+    qg = q.reshape(B, S, KV, H // KV, Dqk)
+    if FORCE_BLOCKWISE or max(S, Sk) > BLOCKWISE_THRESHOLD:
+        out = attend_blockwise(qg, k, v, **kwargs)
+    else:
+        out = attend_direct(qg, k, v, **kwargs)
+    return out.reshape(B, S, H, v.shape[-1])
+
+
+# ---------------------------------------------------------------------------
 # Full-sequence attention layer (train / prefill)
 # ---------------------------------------------------------------------------
 
@@ -170,7 +230,6 @@ def attn_apply(p: dict, x: jax.Array, *, cfg: ArchConfig, causal: bool,
     H = n_heads or cfg.n_heads
     KV = n_kv or cfg.n_kv_heads
     D = head_dim or cfg.head_dim
-    G = H // KV
     kv_src = memory if memory is not None else x
     Sk = kv_src.shape[1]
 
@@ -184,23 +243,10 @@ def attn_apply(p: dict, x: jax.Array, *, cfg: ArchConfig, causal: bool,
         q = apply_rope(q, q_pos, cfg.rope_theta)
         k = apply_rope(k, k_pos, cfg.rope_theta)
 
-    scale = cfg.query_scale or D ** -0.5
-    if USE_PALLAS_KERNEL and memory is None and S == Sk:
-        from repro.kernels.ops import flash_attention as _fa_kernel
-        out = _fa_kernel(q.transpose(0, 2, 1, 3),      # (B,H,S,D)
-                         k.transpose(0, 2, 1, 3),      # (B,KV,S,D)
-                         v.transpose(0, 2, 1, 3),
-                         causal=causal, window=window,
-                         logit_cap=cfg.attn_logit_softcap, scale=scale)
-        return out.transpose(0, 2, 1, 3).reshape(B, S, H * D) @ p["wo"]
-
-    q = q.reshape(B, S, KV, G, D)
-    kwargs = dict(q_pos=q_pos, k_pos=k_pos, causal=causal, window=window,
-                  logit_cap=cfg.attn_logit_softcap, scale=scale)
-    if FORCE_BLOCKWISE or max(S, Sk) > BLOCKWISE_THRESHOLD:
-        out = attend_blockwise(q, k, v, **kwargs)
-    else:
-        out = attend_direct(q, k, v, **kwargs)
+    out = attend(q, k, v, causal=causal, window=window,
+                 logit_cap=cfg.attn_logit_softcap,
+                 scale=cfg.query_scale or D ** -0.5,
+                 q_pos=positions, cross=memory is not None)
     return out.reshape(B, S, H * D) @ p["wo"]
 
 

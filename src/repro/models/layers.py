@@ -111,6 +111,15 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return x * r * (1.0 + scale).astype(dtype)
 
 
+def data_mesh():
+    """The context mesh when it splits the batch over ``"data"`` and over
+    no other axis (the elastic trainer's data-parallel step), else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("data", 1) == 1:
+        return None
+    return mesh if mesh.size == mesh.shape["data"] else None
+
+
 def softcap(x: jax.Array, cap: float) -> jax.Array:
     if not cap:
         return x

@@ -16,8 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.models.attention import NEG_INF, attend_blockwise, attend_direct, \
-    BLOCKWISE_THRESHOLD
+from repro.models.attention import NEG_INF, attend
 from repro.models.layers import (ParamDef, apply_rope, dense_def, rms_norm,
                                  softmax_scale)
 
@@ -68,19 +67,10 @@ def mla_apply(p: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, s, h, rope_d))], axis=-1)
 
-    # KV == H heads, group size 1; pad V up to qk dim not needed — attend_*
-    # contracts q·k on last dim and p·v on v's own dim, but our helpers
-    # assume same head_dim.  Pad v to qk (zeros) and slice after.
+    # KV == H heads; q and k at qk, v at its own vd
     with jax.named_scope("mla/attend"):
-        qh = q_full.reshape(b, s, h, 1, qk)
-        v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - vd)))
-        kwargs = dict(q_pos=pos, k_pos=pos, causal=True, window=0,
-                      logit_cap=0.0, scale=softmax_scale(qk, cfg.yarn))
-        if s > BLOCKWISE_THRESHOLD:
-            out = attend_blockwise(qh, k, v_pad, **kwargs)
-        else:
-            out = attend_direct(qh, k, v_pad, **kwargs)
-        out = out.reshape(b, s, h, qk)[..., :vd]
+        out = attend(q_full, k, v, causal=True, window=0, logit_cap=0.0,
+                     scale=softmax_scale(qk, cfg.yarn))
     with jax.named_scope("mla/out"):
         return out.reshape(b, s, h * vd) @ p["wo"]
 

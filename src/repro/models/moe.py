@@ -49,7 +49,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, MoEConfig, RoutingConfig
-from repro.models.layers import ACTIVATIONS, ParamDef, mlp_apply, mlp_defs
+from repro.models.layers import (ACTIVATIONS, ParamDef, data_mesh, mlp_apply,
+                                 mlp_defs)
 
 
 def moe_defs(cfg: ArchConfig, model_shards: int = 1, dtype=jnp.float32) -> dict:
@@ -279,15 +280,6 @@ def _experts_dropless(p: dict, x2d: jax.Array, top_idx: jax.Array,
     return y.astype(x2d.dtype)
 
 
-def _data_mesh():
-    """The context mesh when it splits the batch over ``"data"`` and over
-    no other axis (the elastic trainer's data-parallel step), else None."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.shape.get("data", 1) == 1:
-        return None
-    return mesh if mesh.size == mesh.shape["data"] else None
-
-
 def _experts_dropless_sharded(p: dict, x: jax.Array, top_idx: jax.Array,
                               top_vals: jax.Array, activation: str,
                               first_held: Optional[int] = None) -> jax.Array:
@@ -307,7 +299,7 @@ def _experts_dropless_sharded(p: dict, x: jax.Array, top_idx: jax.Array,
             top_vals.reshape(bl * s, k), activation,
             first_held).reshape(bl, s, d)
 
-    mesh = _data_mesh()
+    mesh = data_mesh()
     if mesh is not None:
         local = jax.shard_map(local, mesh=mesh,
                               in_specs=(P(), P("data"), P("data"),

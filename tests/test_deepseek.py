@@ -91,9 +91,9 @@ def test_yarn_attention_scale_is_m_squared():
 
 
 def test_mla_matches_the_reference_and_its_gradients():
-    """The program's training-path MLA (YaRN rope, ``m ** 2`` scale, padded
-    V, materialised scores) against the reference's (split nope / rope
-    logits, no padding), output and every gradient, at HIGHEST."""
+    """The program's training-path MLA (YaRN rope, ``m ** 2`` scale, V at
+    its own head size, materialised scores) against the reference's
+    (split nope / rope logits), output and every gradient, at HIGHEST."""
     from repro.models import mla
     cfg = get_arch("deepseek-v2-lite-16b").reduced()
     assert cfg.yarn is not None
